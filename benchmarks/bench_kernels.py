@@ -73,7 +73,7 @@ def chain_vs_dense(emit=print):
     )
     # correctness spot check of the pallas interpret path on a small shape
     xs, Us, Ss, Vs = x[:64, :256], U[:256], S, V[:256]
-    y_k = lowrank_apply(xs, Us, Ss, Vs, True)
+    y_k = lowrank_apply(xs, Us, Ss, Vs, "interpret")
     y_r = ref.lowrank_matmul_ref(xs, Us, Ss, Vs)
     err = float(jnp.abs(y_k - y_r).max())
     emit(f"kernel_pallas_interpret_check,0.0,max_err={err:.2e}")
@@ -117,7 +117,7 @@ def fused_chain_rows(emit=print):
         us_xla = _timeit(jax.jit(xla_fwd_bwd), x, U, S, V)
 
         # interpret-mode parity of the fused kernel branch (not timed)
-        g_k = fwd_bwd(x, U, S, V, True)
+        g_k = fwd_bwd(x, U, S, V, "interpret")
         g_r = fwd_bwd(x, U, S, V, False)
         err = max(
             float(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32)).max())

@@ -98,6 +98,9 @@ def main() -> None:
         "(default: results)",
     )
     args = ap.parse_args()
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     only = set(args.only.split(",")) if args.only else None
     q = args.quick or args.smoke
     git_sha = _git_sha()

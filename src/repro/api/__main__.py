@@ -11,6 +11,7 @@ import argparse
 import sys
 
 from repro.api.spec import load_spec
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def _load(path, overrides):
@@ -49,6 +50,7 @@ def main(argv=None):
     p_srv.add_argument("--requests", type=int, default=8,
                        help="number of synthetic prompts")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.cmd == "validate":
         ok = True

@@ -14,15 +14,14 @@ that make the round-trip lossless:
 
 The TOML dialect is the flat subset the specs need — top-level scalars
 plus one ``[table]`` per sub-spec, string/bool/int/float values.  Reading
-prefers :mod:`tomllib` when the interpreter has it (3.11+) and falls back
-to a small built-in parser of the same subset on 3.10.
+is the stdlib :mod:`tomllib`.
 """
 from typing import Union, get_args, get_origin
 
-import contextlib
 import dataclasses
 import hashlib
 import json
+import tomllib
 
 # ---------------------------------------------------------------------------
 # dataclass ↔ plain dict
@@ -153,66 +152,9 @@ def toml_dumps(data: dict) -> str:
     return "\n".join(lines) + "\n"
 
 
-def _parse_toml_scalar(s: str, where: str):
-    if s.startswith('"'):
-        out, i = [], 1
-        while i < len(s):
-            c = s[i]
-            if c == "\\":
-                if i + 1 >= len(s):
-                    raise ValueError(f"{where}: dangling escape in {s!r}")
-                out.append(s[i + 1])
-                i += 2
-                continue
-            if c == '"':
-                return "".join(out)
-            out.append(c)
-            i += 1
-        raise ValueError(f"{where}: unterminated string {s!r}")
-    s = s.split("#", 1)[0].strip()
-    if s == "true":
-        return True
-    if s == "false":
-        return False
-    with contextlib.suppress(ValueError):
-        return int(s)
-    try:
-        return float(s)
-    except ValueError:
-        raise ValueError(
-            f"{where}: cannot parse value {s!r} (expected string/bool/"
-            f"int/float)"
-        ) from None
-
-
 def toml_loads(text: str) -> dict:
-    """Parse the flat TOML subset ``toml_dumps`` writes (stdlib
-    :mod:`tomllib` when available, built-in fallback on 3.10)."""
-    with contextlib.suppress(ModuleNotFoundError):
-        import tomllib
-
-        return tomllib.loads(text)
-    out: dict = {}
-    current = out
-    for lineno, raw in enumerate(text.splitlines(), 1):
-        line = raw.strip()
-        if not line or line.startswith("#"):
-            continue
-        where = f"line {lineno}"
-        if line.startswith("["):
-            end = line.find("]")
-            if end < 0:
-                raise ValueError(f"{where}: malformed table header {line!r}")
-            name = line[1:end].strip()
-            if not name:
-                raise ValueError(f"{where}: empty table name")
-            current = out.setdefault(name, {})
-            continue
-        key, eq, value = line.partition("=")
-        if not eq:
-            raise ValueError(f"{where}: expected 'key = value', got {line!r}")
-        current[key.strip()] = _parse_toml_scalar(value.strip(), where)
-    return out
+    """Parse a spec file's TOML (stdlib :mod:`tomllib`)."""
+    return tomllib.loads(text)
 
 
 # ---------------------------------------------------------------------------

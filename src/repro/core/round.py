@@ -314,15 +314,18 @@ def _per_client_bytes(shared_bytes, batched_bytes, num_clients: int):
     Static codec counts are python ints whose batched totals divide evenly
     over the ``C`` equal-size client slices — integer arithmetic keeps the
     measured == analytic contract exact up to int32 range (~2 GiB/client/
-    direction) instead of f32's 2^24 bytes.  Traced counts (topk_rank's
-    rank-dependent meter) take the f32 path.
+    direction) instead of f32's 2^24 bytes.  Beyond int32 (a 7B model's
+    identity-codec payload) the count leaves jit as f32, like the analytic
+    figure.  Traced counts (topk_rank's rank-dependent meter) take the f32
+    path.
     """
     if (
         isinstance(shared_bytes, int)
         and isinstance(batched_bytes, int)
         and batched_bytes % num_clients == 0
     ):
-        return shared_bytes + batched_bytes // num_clients
+        total = shared_bytes + batched_bytes // num_clients
+        return total if total < 2**31 else float(total)
     return jnp.float32(shared_bytes) + jnp.float32(batched_bytes) / num_clients
 
 

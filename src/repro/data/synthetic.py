@@ -173,6 +173,11 @@ def make_classification_data(
     return x, labels
 
 
+#: bound on the transition rows :func:`make_token_stream` keeps (32 MiB of
+#: float64): every row at small vocabularies, a few at 152k
+_CDF_CACHE_FLOATS = 1 << 22
+
+
 def make_token_stream(
     *,
     vocab_size: int = 512,
@@ -186,19 +191,36 @@ def make_token_stream(
     Transition logits ``T = A Bᵀ`` (rank ``rank``): a model with enough
     effective rank can drive cross-entropy towards the chain's conditional
     entropy, so LM training on this stream shows genuine loss descent.
+
+    Host memory is O(vocab·rank): the ``vocab × vocab`` table is never
+    built.  Each step forms the current token's transition row from its
+    factor row, and at most :data:`_CDF_CACHE_FLOATS` floats of such rows
+    are kept for reuse.  ``A[tok] @ Bᵀ`` against a contiguous ``Bᵀ`` is
+    bit-equal to row ``tok`` of the full product, so the stream equals the
+    one the dense table would draw.
     """
     rng = np.random.default_rng(seed)
     A = rng.standard_normal((vocab_size, rank)).astype(np.float32)
-    B = rng.standard_normal((vocab_size, rank)).astype(np.float32)
-    logits = (A @ B.T) / (np.sqrt(rank) * temperature)
-    logits -= logits.max(axis=-1, keepdims=True)
-    probs = np.exp(logits)
-    probs /= probs.sum(axis=-1, keepdims=True)
-    cdf = np.cumsum(probs, axis=-1)
+    Bt = np.ascontiguousarray(
+        rng.standard_normal((vocab_size, rank)).astype(np.float32).T
+    )
+    scale = np.sqrt(rank) * temperature
+    cdfs = {}
+    cap = max(_CDF_CACHE_FLOATS // vocab_size, 1)
     tokens = np.empty(num_tokens, dtype=np.int32)
     tok = int(rng.integers(vocab_size))
     u = rng.random(num_tokens)
     for i in range(num_tokens):
-        tok = int(np.searchsorted(cdf[tok], u[i]))
-        tokens[i] = min(tok, vocab_size - 1)
+        cdf = cdfs.get(tok)
+        if cdf is None:
+            logits = (A[tok] @ Bt) / scale
+            logits -= logits.max()
+            probs = np.exp(logits)
+            probs /= probs.sum()
+            cdf = np.cumsum(probs)
+            if len(cdfs) < cap:
+                cdfs[tok] = cdf
+        tok = min(int(np.searchsorted(cdf, u[i])), vocab_size - 1)
+        tokens[i] = tok
     return tokens
+

@@ -11,10 +11,10 @@ kernels and wires the backward pass through the same primitives:
     dS = (x U)ᵀ (dy V)               [atb kernel — the Ũᵀ(·)Ṽ projection]
     dV = dyᵀ (x U S)                 [atb kernel]
 
-On non-TPU backends (this container) the wrappers fall back to the jnp
-reference implementation unless ``interpret=True`` is forced — Pallas TPU
-kernels only *compile* for TPU; interpret mode executes the kernel body in
-Python for correctness validation (used by tests/benchmarks here).
+``use_kernels=True`` means the compiled TPU kernels and nothing else: on
+a backend that is not a TPU the lowering fails instead of quietly running
+something slower.  The Pallas interpreter (kernel bodies executed in
+Python, for validation on CPU) is reached only by ``"interpret"``.
 
 Padding rules (all exact — padded rows/columns are zero):
 - the rank dim is padded to a multiple of 128 lanes;
@@ -84,13 +84,6 @@ def use_kernels_for(policy: str):
 
     get_hub().counter("kernels.dispatch", policy=policy, resolved=str(flag))
     return flag
-
-
-def _interpret_mode(use_kernels) -> bool:
-    """``use_kernels`` is False / True / "interpret": plain ``True`` means
-    compiled-on-TPU, interpreter elsewhere; ``"interpret"`` forces the
-    interpreter even on TPU."""
-    return use_kernels == "interpret" or not on_tpu()
 
 
 # ---------------------------------------------------------------------------
@@ -193,14 +186,14 @@ def coeff_grad_kernels(x, dy, U, V, *, interpret: bool) -> jax.Array:
 def lowrank_apply(x, U, S, V, use_kernels=False):
     """y = ((x U) S) Vᵀ with a kernel-backed custom VJP.
 
-    ``use_kernels``: ``True`` → the Pallas path (compiled on TPU, interpret
-    elsewhere); ``"interpret"`` → the Pallas path through the interpreter
-    on *every* backend (overrides the compiled path on TPU too); ``False``
-    → pure-jnp reference (XLA fuses well on its own for small sizes).
+    ``use_kernels``: ``True`` → the compiled Pallas kernels (TPU only);
+    ``"interpret"`` → the Pallas path through the interpreter on *every*
+    backend; ``False`` → pure-jnp reference (XLA fuses well on its own for
+    small sizes).
     """
     if use_kernels:
         return lowrank_apply_kernels(
-            x, U, S, V, interpret=_interpret_mode(use_kernels)
+            x, U, S, V, interpret=use_kernels == "interpret"
         )
     return ref.lowrank_matmul_ref(x, U, S, V)
 
@@ -212,7 +205,7 @@ def _fwd(x, U, S, V, use_kernels):
 
 def _bwd(use_kernels, resids, dy):
     x, U, S, V = resids
-    interpret = _interpret_mode(use_kernels)
+    interpret = use_kernels == "interpret"
 
     if use_kernels:
         U_, S_, V_ = _pad_rank(U, S, V)

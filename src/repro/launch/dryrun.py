@@ -1,15 +1,20 @@
 import os
 
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 # --------------------------------------------------------------------------
 # Multi-pod dry-run (deliverable e): lower + compile every
 # (architecture × input shape) on the production meshes, print
 # memory_analysis / cost_analysis, and persist the roofline terms.
 #
-# The XLA_FLAGS line above MUST run before any other import — jax locks the
-# device count at first initialization.  Do not move it; do not set this
-# flag anywhere global (tests/benches must see the single real CPU device).
+# The two environment lines above MUST run before any other import — jax
+# locks the device count and the platform at first initialization.  This
+# is a compile-only tool on fake host devices: pinning the CPU platform
+# keeps it (and every --all child, which inherits the variable) off an
+# attached accelerator, which belongs to one process at a time.  Do not
+# move them; do not set these anywhere global (tests/benches must see the
+# single real CPU device).
 #
 # Usage:
 #   python -m repro.launch.dryrun --arch qwen2-7b --shape train_4k

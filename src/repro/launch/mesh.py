@@ -14,23 +14,21 @@ from __future__ import annotations
 import jax
 
 
-def mesh_kwargs(num_axes: int) -> dict:
-    """``axis_types`` only exists on jax ≥ 0.5 (where explicit-sharding
-    AxisTypes were introduced); older versions are Auto-only anyway."""
-    if hasattr(jax.sharding, "AxisType"):
-        return {"axis_types": (jax.sharding.AxisType.Auto,) * num_axes}
-    return {}
+#: GSPMD partitions these meshes (``jax.make_mesh`` defaults to Explicit)
+_AUTO = jax.sharding.AxisType.Auto
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **mesh_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(_AUTO,) * len(axes))
 
 
 def make_host_mesh(data: int = 2, model: int = 2) -> jax.sharding.Mesh:
     """Small mesh over forced host devices (unit tests)."""
-    return jax.make_mesh((data, model), ("data", "model"), **mesh_kwargs(2))
+    return jax.make_mesh(
+        (data, model), ("data", "model"), axis_types=(_AUTO, _AUTO)
+    )
 
 
 def data_axis_size(mesh: jax.sharding.Mesh) -> int:

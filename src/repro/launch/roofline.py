@@ -127,8 +127,6 @@ class Roofline:
 
 def from_compiled(compiled) -> Roofline:
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # jax < 0.5 returns [dict] per device
-        cost = cost[0] if cost else {}
     flops = float(cost.get("flops", 0.0))
     bytes_acc = float(cost.get("bytes accessed", 0.0))
     coll = collective_bytes(compiled.as_text())

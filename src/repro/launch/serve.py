@@ -100,7 +100,9 @@ def main(argv=None):
                     default="continuous")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    from repro.utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     bucket = max(8, args.prompt_len // 4)
     max_prompt = -(-args.prompt_len // bucket) * bucket
     spec = ExperimentSpec(

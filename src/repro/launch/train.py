@@ -201,7 +201,10 @@ def spec_from_argv(argv=None) -> ExperimentSpec:
 
 
 def main(argv=None):
+    from repro.utils.compile_cache import enable_compile_cache
+
     spec = spec_from_argv(argv)
+    enable_compile_cache()
     exp = build(spec)
     print(f"{exp.task.description} clients={spec.fed.clients} "
           f"[spec {spec.spec_hash()}]")

@@ -58,8 +58,16 @@ def test_lowrank_custom_vjp_matches_reference(M, K, N, R):
             argnums=(0, 1, 2, 3),
         )(x, U, S, V)
 
-    for a, b in zip(loss(False), loss(True)):
+    for a, b in zip(loss(False), loss("interpret")):
         np.testing.assert_allclose(a, b, rtol=1e-3, atol=1e-3)
+
+
+def test_compiled_flag_never_falls_back_to_the_interpreter():
+    """``use_kernels=True`` is the compiled TPU path only: on the CPU it
+    fails loudly instead of quietly running the interpreter."""
+    x, U, S, V = _inputs(16, 128, 64, 8, jnp.float32)
+    with pytest.raises(ValueError, match="interpret"):
+        lowrank_apply(x, U, S, V, True)
 
 
 def test_coeff_grad_projection():

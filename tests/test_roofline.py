@@ -56,18 +56,17 @@ def test_roofline_terms_and_dominance():
 
 def test_real_compiled_module_collectives():
     """An actual psum lowering must be detected by the parser."""
-    from repro.launch.mesh import mesh_kwargs
-
-    mesh = jax.make_mesh((1,), ("x",), **mesh_kwargs(1))
+    mesh = jax.make_mesh(
+        (1,), ("x",), axis_types=(jax.sharding.AxisType.Auto,)
+    )
 
     def f(a):
         return jax.lax.psum(a, "x")
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     g = jax.jit(
-        shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P())
+        jax.shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P())
     )
     c = g.lower(jax.ShapeDtypeStruct((8, 8), jnp.float32)).compile()
     out = collective_bytes(c.as_text())

@@ -66,6 +66,46 @@ def test_token_stream_is_learnable_markov():
     assert H_cond < H_uni - 0.1
 
 
+def _dense_table_stream(vocab_size, num_tokens, rank, seed):
+    """The stream drawn from the full ``vocab × vocab`` transition table."""
+    rng = np.random.default_rng(seed)
+    A = rng.standard_normal((vocab_size, rank)).astype(np.float32)
+    B = rng.standard_normal((vocab_size, rank)).astype(np.float32)
+    logits = (A @ B.T) / np.sqrt(rank)
+    logits -= logits.max(axis=-1, keepdims=True)
+    probs = np.exp(logits)
+    probs /= probs.sum(axis=-1, keepdims=True)
+    cdf = np.cumsum(probs, axis=-1)
+    out = np.empty(num_tokens, dtype=np.int32)
+    tok = int(rng.integers(vocab_size))
+    u = rng.random(num_tokens)
+    for i in range(num_tokens):
+        tok = min(int(np.searchsorted(cdf[tok], u[i])), vocab_size - 1)
+        out[i] = tok
+    return out
+
+
+@pytest.mark.parametrize("vocab,rank", [(64, 4), (512, 16), (8192, 16)])
+def test_token_stream_equals_dense_table_chain(vocab, rank):
+    want = _dense_table_stream(vocab, 20_000, rank, seed=3)
+    got = make_token_stream(vocab_size=vocab, num_tokens=20_000, rank=rank, seed=3)
+    np.testing.assert_array_equal(got, want)
+
+
+def test_token_stream_real_vocab_memory_is_linear():
+    """At Qwen2's 152,064 tokens the dense table would be 92 GB of f32."""
+    import tracemalloc
+
+    tracemalloc.start()
+    try:
+        toks = make_token_stream(vocab_size=152_064, num_tokens=300, rank=16)
+        peak = tracemalloc.get_traced_memory()[1]
+    finally:
+        tracemalloc.stop()
+    assert toks.min() >= 0 and toks.max() < 152_064
+    assert peak < 128 * 2**20, peak
+
+
 def test_checkpoint_roundtrip(tmp_path, rng_key):
     params = {
         "layer": {

@@ -1,0 +1,145 @@
+"""Compile rehearsals of the main-path Pallas kernels for a described TPU v5e.
+
+Nothing runs: each case compiles one kernel program at Qwen2-7B's widths
+(q/o, k/v, down-proj, lm-head, embedding) at rank 256 and the augmented
+512, in bf16, against a ``v5e:2x2`` topology that is described, not
+attached.  The TPU compiler refuses here what interpret mode cannot see:
+tiles not aligned to the hardware, more fast memory than a kernel may use,
+operand layouts Mosaic does not lower.
+
+The topology is described inside a fixture (never while a module is
+imported), and every test that needs it lives in this one file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+import repro.kernels.ops as ops
+from repro.configs import get_config
+from repro.kernels.coeff_grad import atb
+from repro.kernels.lowrank_matmul import avt, xus
+
+BF16 = jnp.bfloat16
+QWEN = get_config("qwen2-7b")
+D, FF, VOCAB = QWEN.d_model, QWEN.d_ff, QWEN.vocab_size
+KV = QWEN.num_kv_heads * QWEN.hd
+M = 512  # batch 4 × seq 128, one client's tokens per step
+
+# (label, K, N): the factorized matmuls of a Qwen2-7B layer stack
+SHAPES = [
+    ("q_o", D, D),
+    ("k_v", D, KV),
+    ("down", FF, D),
+    ("lm_head", D, VOCAB),
+    ("embed", None, D),  # gathered U rows: K is the rank itself
+]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", os.environ.get("TPU_LOG_DIR", "disabled"))
+        try:
+            desc = topologies.get_topology_desc(
+                platform="tpu", topology_name="v5e:2x2"
+            )
+        except Exception as e:  # no TPU compiler in this installation
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        # a compile for a described chip cannot be read back from the
+        # persistent cache without the chip: keep the cache out of it
+        enabled = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        yield desc
+        jax.config.update("jax_enable_compilation_cache", enabled)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(one_chip, *shape, dtype=BF16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+def _compiled_text(fn, *args) -> str:
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+@pytest.mark.parametrize("R", [256, 512])
+@pytest.mark.parametrize("K", [D, FF])
+def test_xus_compiles(one_chip, K, R):
+    _compiled_text(
+        lambda x, U, S: xus(x, U, S),
+        _sds(one_chip, M, K), _sds(one_chip, K, R),
+        _sds(one_chip, R, R, dtype=jnp.float32),
+    )
+
+
+@pytest.mark.parametrize("R", [256, 512])
+@pytest.mark.parametrize("N", [D, VOCAB])
+def test_avt_compiles(one_chip, N, R):
+    _compiled_text(
+        lambda A, V: avt(A, V), _sds(one_chip, M, R), _sds(one_chip, N, R)
+    )
+
+
+@pytest.mark.parametrize("R", [256, 512])
+@pytest.mark.parametrize("Ka", [D, FF, VOCAB])
+def test_atb_compiles(one_chip, Ka, R):
+    """The transposed-LHS contraction: dU (Ka = n_in) and dV (Ka = n_out)."""
+    _compiled_text(
+        lambda A, B: atb(A, B), _sds(one_chip, M, Ka), _sds(one_chip, M, R)
+    )
+
+
+def _factor_args(one_chip, label, K, N, R):
+    if label == "embed":
+        K = R
+    elif label == "k_v":
+        R //= 4  # r_max 64 (augmented 128) on the 512-wide k/v projections
+    return (
+        _sds(one_chip, M, K), _sds(one_chip, K, R),
+        _sds(one_chip, R, R), _sds(one_chip, N, R),
+    )
+
+
+@pytest.mark.parametrize("R", [256, 512])
+@pytest.mark.parametrize("label,K,N", SHAPES, ids=[s[0] for s in SHAPES])
+def test_lowrank_apply_forward_compiles(one_chip, monkeypatch, label, K, N, R):
+    """``kernels="auto"`` resolves to the compiled chain on a TPU."""
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    use = ops.use_kernels_for("auto")
+    assert use is True
+    text = _compiled_text(
+        lambda x, U, S, V: ops.lowrank_apply(x, U, S, V, use),
+        *_factor_args(one_chip, label, K, N, R),
+    )
+    assert text.count("tpu_custom_call") >= 2  # xus then avt
+
+
+@pytest.mark.parametrize("R", [256, 512])
+@pytest.mark.parametrize("label,K,N", SHAPES, ids=[s[0] for s in SHAPES])
+def test_lowrank_apply_vjp_compiles(one_chip, monkeypatch, label, K, N, R):
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    use = ops.use_kernels_for("auto")
+
+    def fwd_bwd(x, U, S, V):
+        def loss(*a):
+            return jnp.sum(ops.lowrank_apply(*a, use).astype(jnp.float32))
+
+        return jax.grad(loss, argnums=(0, 1, 2, 3))(x, U, S, V)
+
+    text = _compiled_text(fwd_bwd, *_factor_args(one_chip, label, K, N, R))
+    # forward xus/avt plus the backward's xus ×4, avt and atb ×3
+    assert text.count("tpu_custom_call") >= 8

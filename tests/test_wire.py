@@ -412,3 +412,14 @@ def test_fedconfig_rejects_bad_hyperparameters(kwargs, match):
 def test_fedconfig_accepts_boundary_values():
     FedConfig(num_clients=1, s_star=1, lr=1e-8, tau=0.0)
     FedConfig(num_clients=4, s_star=2, tau=0.999)
+
+
+def test_per_client_bytes_beyond_int32_leave_jit():
+    """A 7B model's identity payload (> 2 GiB per client) must not overflow
+    the int32 a python int becomes when it leaves jit."""
+    from repro.core.round import _per_client_bytes
+
+    assert _per_client_bytes(100, 8, 4) == 102  # exact int in range
+    big = jax.jit(lambda: _per_client_bytes(5_606_497_048, 8, 4))()
+    assert big.dtype == jnp.float32
+    assert float(big) == pytest.approx(5_606_497_050, rel=1e-7)
