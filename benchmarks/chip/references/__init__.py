@@ -1,0 +1,1 @@
+"""Plain references: float32 jax.numpy, no kernels, nothing of the program."""
