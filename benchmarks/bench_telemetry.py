@@ -1,8 +1,9 @@
 """Telemetry-hub overhead benchmark: the cost of observing a run.
 
-The hub's contract is "a disabled hub is a near-zero no-op, an enabled
-hub costs microseconds per event" — this group pins that, per hot-path
-operation and end-to-end through a real engine:
+The hub's contract is "a disabled hub builds no event and its span is
+the profiler annotation alone (about a microsecond while no profiler
+collects), an enabled hub costs microseconds per event" — this group
+pins that, per hot-path operation and end-to-end through a real engine:
 
   telemetry_span_disabled   `with hub.span(...)` on a disabled hub
   telemetry_span_memory     same span on an enabled hub → MemorySink

@@ -350,12 +350,13 @@ class TelemetrySpec:
     """Structured telemetry (:mod:`repro.telemetry`): round/phase spans,
     metric streams, JSONL event logs and Perfetto traces.
 
-    ``enabled=False`` (the default) keeps the console progress sink only —
-    runs look exactly as before.  ``sinks`` is a comma-separated subset of
-    ``console``, ``memory``, ``jsonl``, ``perfetto``; file sinks write
-    ``events.jsonl`` / ``trace.json`` under ``dir``.  ``sample_every``
-    keeps every Nth round's gauge/hist events (spans, counters, and
-    progress are never sampled).  Telemetry only ever *reads* run state,
+    ``enabled=False`` (the default) keeps the console progress lines only —
+    runs look exactly as before, and spans reach only a profiler trace
+    (``jax.profiler``) when one is collected.  ``sinks`` is a
+    comma-separated subset of ``console``, ``memory``, ``jsonl``,
+    ``perfetto``; file sinks write ``events.jsonl`` / ``trace.json`` under
+    ``dir``.  ``sample_every`` keeps every Nth round's gauge/hist events
+    (spans, counters, and progress are never sampled).  Telemetry only ever *reads* run state,
     so enabling it cannot change params or history.
     """
 

@@ -70,4 +70,5 @@ def atb(
         out_shape=jax.ShapeDtypeStruct((Ka, Kb), A.dtype),
         scratch_shapes=[pltpu.VMEM((bka, Kb), jnp.float32)],
         interpret=interpret,
+        name="atb",
     )(A, B)

@@ -143,5 +143,6 @@ def flash_attention(
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(qp, kp, qr, kr, vr)
     return out.reshape(B, H, Tq, d).transpose(0, 2, 1, 3)

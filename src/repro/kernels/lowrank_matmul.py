@@ -97,6 +97,7 @@ def xus(x: jax.Array, U: jax.Array, S: jax.Array, *, bm: int = DEFAULT_BM,
         out_shape=jax.ShapeDtypeStruct((M, R), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, R), jnp.float32)],
         interpret=interpret,
+        name="xus",
     )(x, U, S.astype(jnp.float32))
 
 
@@ -129,4 +130,5 @@ def avt(A: jax.Array, V: jax.Array, *, bm: int = DEFAULT_BM,
         out_specs=pl.BlockSpec((bm, bn), lambda mi, nj: (mi, nj)),
         out_shape=jax.ShapeDtypeStruct((M, N), A.dtype),
         interpret=interpret,
+        name="avt",
     )(A, V)

@@ -25,6 +25,12 @@ Sampling is deterministic and batching-invariant: token ``j`` of request
 ``rid`` draws from ``fold_in(fold_in(key(seed), rid), j)``, so a request's
 output is independent of which other requests share the batch (dense
 families — exactly the ones ``init_cache(per_slot=True)`` admits).
+
+Spans (the engine's hub; see ``repro.telemetry.hub``): ``serve.prefill``
+(attr ``bucket``), ``serve.insert`` and ``serve.decode_step`` cover their
+executable's dispatch; ``serve.sample`` (attr ``rows``) covers the
+sampler's dispatch, the wait for the device and the copy of the tokens
+to the host, so it is where the host waits on the device.
 """
 from __future__ import annotations
 
@@ -204,28 +210,37 @@ class ServeEngine:
             self._prefill_fns[lb] = fn
         tokens = np.zeros((1, lb), np.int32)
         tokens[0, :length] = prompt
-        return fn(self.params, jnp.asarray(tokens), jnp.int32(length - 1))
+        with self.hub.span("serve.prefill", bucket=lb):
+            return fn(self.params, jnp.asarray(tokens), jnp.int32(length - 1))
 
     def insert(self, state, cache, slot: int, length: int):
         """Graft a B=1 prefill ``cache`` into ``state`` row ``slot``."""
-        return self._insert_fn(state, cache, jnp.int32(slot), jnp.int32(length))
+        with self.hub.span("serve.insert"):
+            return self._insert_fn(
+                state, cache, jnp.int32(slot), jnp.int32(length)
+            )
 
     # ------------------------------------------------------------ decode
 
     def step(self, state, last_tokens):
         """One decode step over all slots: (B,) tokens → (logits, state)."""
-        tokens = jnp.asarray(last_tokens, jnp.int32).reshape(self.max_batch, 1)
-        return self._step_fn(self.params, state, tokens)
+        with self.hub.span("serve.decode_step"):
+            tokens = jnp.asarray(last_tokens, jnp.int32).reshape(
+                self.max_batch, 1
+            )
+            return self._step_fn(self.params, state, tokens)
 
     def sample(self, logits, rids, steps) -> np.ndarray:
         """Batching-invariant sampling: greedy at temperature 0, else a
-        categorical draw keyed on (seed, rid, token index)."""
-        out = self._sample_fn(
-            jnp.asarray(logits),
-            jnp.asarray(rids, jnp.int32),
-            jnp.asarray(steps, jnp.int32),
-        )
-        return np.asarray(out)
+        categorical draw keyed on (seed, rid, token index).  Returns once
+        the tokens are on the host."""
+        with self.hub.span("serve.sample", rows=len(rids)):
+            out = self._sample_fn(
+                jnp.asarray(logits),
+                jnp.asarray(rids, jnp.int32),
+                jnp.asarray(steps, jnp.int32),
+            )
+            return np.asarray(out)
 
     # ----------------------------------------------------------- costing
 

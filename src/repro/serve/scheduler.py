@@ -15,9 +15,16 @@ and independent of batching mode.  All host timing goes through
 ``repro.telemetry.clock.perf_seconds`` (RPL003).
 
 Telemetry per request: a ``serve.queued`` wall span (submit→admit), a
-``serve.prefill`` span, a ``serve.decode`` wall span (admit→finish),
-``serve.tokens`` counters and ``serve.queue_depth`` / ``serve.active``
-gauges — p50/p99 latency falls out of the standard Perfetto export.
+``serve.decode`` wall span (first token on the host→finish),
+``serve.tokens`` counters and a ``serve.queue_depth`` gauge at submit —
+p50/p99 latency falls out of the standard Perfetto export.  Per step, in
+the profiler's trace as well as the hub's sinks: ``serve.step`` (attrs
+``step``, ``active``, ``queued``, read as the step starts) around the
+whole step; inside it one ``serve.admit`` (attrs ``rid``, ``prompt_len``,
+``bucket``) per admission, from the prefill's dispatch until the first
+token is on the host and the insert is dispatched; the engine's decode
+step and sample; and ``serve.emit`` (attr ``done``, the requests it
+completed) around the loop over the slots.
 """
 from __future__ import annotations
 
@@ -125,23 +132,26 @@ class ContinuousScheduler:
     def _admit(self, slot_i: int, req: Request, t_submit: float,
                submit_step: int) -> Optional[Completion]:
         t_admit = perf_seconds()
-        with self.hub.span("serve.prefill", rid=req.rid):
+        prompt_len = int(np.asarray(req.tokens).size)
+        with self.hub.span("serve.admit", rid=req.rid, prompt_len=prompt_len,
+                           bucket=self.engine.bucket_len(prompt_len)):
             logits, cache = self.engine.prefill(req.tokens)
-        prefill_s = perf_seconds() - t_admit
-        first = int(
-            self.engine.sample(logits, np.int32([req.rid]), np.int32([0]))[0]
-        )
-        slot = _Slot(
-            rid=req.rid, prompt_len=int(np.asarray(req.tokens).size),
-            budget=self._budget(req), eos_id=req.eos_id, out=[first],
-            t_submit=t_submit, t_admit=t_admit, prefill_s=prefill_s,
-            submit_step=submit_step, admit_step=self.step_count,
-        )
-        if len(slot.out) >= slot.budget or first == slot.eos_id:
-            return self._complete(slot)  # done at prefill; slot never bound
-        self.state = self.engine.insert(
-            self.state, cache, slot_i, slot.prompt_len
-        )
+            first = int(self.engine.sample(
+                logits, np.int32([req.rid]), np.int32([0])
+            )[0])
+            # the prefill ends when its first token is on the host
+            prefill_s = perf_seconds() - t_admit
+            slot = _Slot(
+                rid=req.rid, prompt_len=prompt_len,
+                budget=self._budget(req), eos_id=req.eos_id, out=[first],
+                t_submit=t_submit, t_admit=t_admit, prefill_s=prefill_s,
+                submit_step=submit_step, admit_step=self.step_count,
+            )
+            if len(slot.out) >= slot.budget or first == slot.eos_id:
+                return self._complete(slot)  # done at prefill; never bound
+            self.state = self.engine.insert(
+                self.state, cache, slot_i, slot.prompt_len
+            )
         self.slots[slot_i] = slot
         self._last[slot_i] = first
         self._rids[slot_i] = req.rid
@@ -173,9 +183,16 @@ class ContinuousScheduler:
 
     def step(self) -> List[Completion]:
         """Admit (mode-dependent) + one decode step; returns completions."""
+        active = self.active
+        with self.hub.span("serve.step", step=self.step_count, active=active,
+                           queued=len(self.queue)):
+            done = self._step(active)
+        self.step_count += 1
+        return done
+
+    def _step(self, active: int) -> List[Completion]:
         done: List[Completion] = []
-        may_admit = self.mode == "continuous" or self.active == 0
-        if may_admit:
+        if self.mode == "continuous" or active == 0:
             for i, s in enumerate(self.slots):
                 if not self.queue:
                     break
@@ -184,12 +201,13 @@ class ContinuousScheduler:
                     c = self._admit(i, req, t_submit, submit_step)
                     if c is not None:
                         done.append(c)
-            self.hub.gauge("serve.queue_depth", len(self.queue))
-
-        if self.active:
-            logits, self.state = self.engine.step(self.state, self._last)
-            nxt = self.engine.sample(logits, self._rids, self._tok_idx)
-            self.decode_steps += 1
+        if not self.active:
+            return done
+        logits, self.state = self.engine.step(self.state, self._last)
+        nxt = self.engine.sample(logits, self._rids, self._tok_idx)
+        self.decode_steps += 1
+        with self.hub.span("serve.emit") as emit:
+            admitted = len(done)
             for i, s in enumerate(self.slots):
                 if s is None:
                     continue
@@ -201,8 +219,7 @@ class ContinuousScheduler:
                     done.append(self._complete(s))
                     self.slots[i] = None
                     self._rids[i] = -1
-        self.step_count += 1
-        self.hub.gauge("serve.active", self.active)
+            emit.set_metadata(done=len(done) - admitted)
         return done
 
     # ---------------------------------------------------------------- run

@@ -10,7 +10,8 @@ what the host actually did and what the simulated fleet experienced.
 
 API surface:
 
-- ``with hub.span("round", round=r): ...`` — wall-duration span;
+- ``with hub.span("round", round=r): ...`` — wall-duration span, also
+  written into the profiler's trace (below);
 - ``hub.span_at("client_round", tv0, tv1, client=c)`` — a span on the
   *virtual* clock with explicit endpoints (the async engine's
   dispatch→arrival client rounds, priced by the simulator);
@@ -20,14 +21,23 @@ API surface:
   :class:`~repro.telemetry.sinks.ConsoleSink` (the engines' old
   ``print()`` calls, now one event kind among the rest).
 
+Every span is also a ``jax.profiler.TraceAnnotation`` of the same name,
+with its attrs as the event's stats, around the same interval: whoever
+collects a profiler trace sees the program's spans on the device
+timeline's own clock.  The annotation is inert (about a microsecond)
+while no profiler collects.  Attrs known only when a span ends go in
+through the ``set_metadata(**attrs)`` of what ``with hub.span(...) as sp``
+binds.
+
 The load-bearing invariant (pinned in ``tests/test_telemetry.py``):
 telemetry **reads state and never writes it** — no RNG draws, no virtual
 clock advances, no engine mutation — so a telemetry-enabled run is
 bit-for-bit identical to a disabled one.  A disabled hub
-(``enabled=False``, e.g. :data:`NULL_HUB`) short-circuits every call
-before any event dict is built and hands out one cached no-op context
-manager, making it near-zero overhead (pinned by
-``benchmarks/bench_telemetry.py``).
+(``enabled=False``, e.g. :data:`NULL_HUB`) builds no event: its
+counters, gauges and hists return at once, and its spans are the
+profiler annotation alone.  Only its progress lines still render, on
+the :class:`~repro.telemetry.sinks.ConsoleSink` among its sinks (the
+cost is pinned by ``benchmarks/bench_telemetry.py``).
 
 Gauges and hists that carry a ``round=`` attr respect ``sample_every``:
 only rounds divisible by the cadence are recorded — spans, counters and
@@ -38,10 +48,28 @@ from __future__ import annotations
 import contextlib
 from typing import List, Optional
 
+from jax.profiler import TraceAnnotation
+
 from repro.telemetry.clock import perf_seconds, wall_time
-from repro.telemetry.sinks import make_sinks
+from repro.telemetry.sinks import ConsoleSink, make_sinks
 
 _UNSET = object()
+
+
+class _Span:
+    """What ``with hub.span(...) as sp`` binds on an enabled hub:
+    ``sp.set_metadata(**attrs)`` adds attrs to the sink event and to the
+    annotation alike (a disabled hub binds the annotation itself, which
+    has the same method)."""
+
+    __slots__ = ("annotation", "attrs")
+
+    def __init__(self, annotation, attrs):
+        self.annotation, self.attrs = annotation, attrs
+
+    def set_metadata(self, **attrs) -> None:
+        self.attrs.update(attrs)
+        self.annotation.set_metadata(**attrs)
 
 
 class TelemetryHub:
@@ -62,7 +90,6 @@ class TelemetryHub:
         self._clock = clock  # duck-typed: anything with a float `.now`
         self._seq = 0
         self._epoch = perf_seconds()
-        self._noop = contextlib.nullcontext()
         if self.enabled and self.sinks:
             self._emit(
                 "meta", "hub_start",
@@ -82,7 +109,7 @@ class TelemetryHub:
     # -- emission core -----------------------------------------------------
 
     def _emit(self, kind, name, *, t=None, dur=None, tv=_UNSET, durv=None,
-              value=None, attrs=None):
+              value=None, attrs=None, sinks=None):
         event = {
             "kind": kind,
             "name": name,
@@ -95,7 +122,7 @@ class TelemetryHub:
             "seq": self._seq,
         }
         self._seq += 1
-        for sink in self.sinks:
+        for sink in self.sinks if sinks is None else sinks:
             sink.emit(event)
 
     def _sampled(self, attrs: dict) -> bool:
@@ -110,8 +137,10 @@ class TelemetryHub:
     def _span_cm(self, name, attrs):
         t0 = perf_seconds()
         tv0 = self.virtual_now()
+        annotation = TraceAnnotation(name, **attrs)
         try:
-            yield
+            with annotation:
+                yield _Span(annotation, attrs)
         finally:
             self._emit(
                 "span", name,
@@ -124,9 +153,11 @@ class TelemetryHub:
     def span(self, name: str, **attrs):
         """Context manager timing a wall-clock span (virtual time is
         stamped at entry for context; virtual *durations* come from
-        :meth:`span_at`, which the simulators price explicitly)."""
+        :meth:`span_at`, which the simulators price explicitly), written
+        into the profiler's trace as well; a disabled hub's span is that
+        annotation alone."""
         if not self.enabled:
-            return self._noop
+            return TraceAnnotation(name, **attrs)
         return self._span_cm(name, attrs)
 
     def span_at(self, name: str, tv_start: float, tv_end: float, **attrs):
@@ -179,10 +210,15 @@ class TelemetryHub:
     # -- progress / lifecycle ----------------------------------------------
 
     def progress(self, message: str, **attrs) -> None:
-        """A human-facing progress line (rendered by ConsoleSink)."""
+        """A human-facing progress line (rendered by ConsoleSink), which
+        a disabled hub still hands to its console sinks."""
+        sinks = None
         if not self.enabled:
-            return
-        self._emit("progress", "progress", attrs={"message": message, **attrs})
+            sinks = [s for s in self.sinks if isinstance(s, ConsoleSink)]
+            if not sinks:
+                return
+        self._emit("progress", "progress", attrs={"message": message, **attrs},
+                   sinks=sinks)
 
     def flush(self) -> None:
         for sink in self.sinks:
@@ -210,8 +246,6 @@ def default_hub() -> TelemetryHub:
     """The console-only hub engines fall back to when built without one."""
     global _DEFAULT_HUB
     if _DEFAULT_HUB is None:
-        from repro.telemetry.sinks import ConsoleSink
-
         _DEFAULT_HUB = TelemetryHub(sinks=(ConsoleSink(),))
     return _DEFAULT_HUB
 
@@ -233,12 +267,12 @@ def hub_from_spec(tspec, *, meta: Optional[dict] = None) -> TelemetryHub:
     """Build a hub from a ``TelemetrySpec``-shaped object (duck-typed:
     ``enabled`` / ``sinks`` / ``dir`` / ``sample_every``).
 
-    Disabled specs return the console-only default hub — progress lines
-    keep printing exactly as before telemetry existed, and no event log
-    is written.
+    A disabled spec gives a disabled hub with a console sink: progress
+    lines keep printing exactly as before telemetry existed, nothing else
+    builds an event, and spans reach only the profiler's trace.
     """
     if not tspec.enabled:
-        return default_hub()
+        return TelemetryHub(sinks=(ConsoleSink(),), enabled=False)
     return TelemetryHub(
         make_sinks(tspec.sinks, out_dir=tspec.dir),
         sample_every=tspec.sample_every,

@@ -434,3 +434,112 @@ def test_serve_telemetry_spans_and_counters():
     ]
     assert len(decode_spans) == 3
     assert all(e["dur"] >= 0 for e in decode_spans)
+
+
+def _host_spans(tmp_path, fn, prefix="serve."):
+    """Run ``fn`` under the profiler; its ``prefix`` host events as
+    (name, start_ns, end_ns, stats), in order of start."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    [path] = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                out.extend(
+                    (e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+                    for e in line.events if e.name.startswith(prefix)
+                )
+    return sorted(out, key=lambda e: (e[1], -e[2]))
+
+
+def _parent(span, spans):
+    """The innermost other span that holds ``span`` whole, or None."""
+    _, s, e, _ = span
+    holders = [p for p in spans if p is not span and p[1] <= s and e <= p[2]]
+    return min(holders, key=lambda p: p[2] - p[1], default=None)
+
+
+def test_serving_spans_form_the_step_tree_in_the_profiler_trace(session, tmp_path):
+    """With telemetry off (the session's spec), the step's spans reach the
+    profiler's trace with their attrs, each inside its parent."""
+    spec = session.spec
+    prompts = prompts_for(spec, n=4, seed=3)
+    session.generate(prompts, arrival_steps=[0, 0, 2, 5])  # compiles
+    spans = _host_spans(
+        tmp_path, lambda: session.generate(prompts, arrival_steps=[0, 0, 2, 5])
+    )
+    steps = [x for x in spans if x[0] == "serve.step"]
+    admits = [x for x in spans if x[0] == "serve.admit"]
+    assert steps and len(admits) == len(prompts)
+    kids = {id(p): [] for p in steps + admits}
+    for x in spans:
+        parent = _parent(x, spans)
+        if x[0] == "serve.step":
+            assert parent is None
+        else:
+            assert parent is not None, x
+            kids[id(parent)].append(x)
+    for st in steps:
+        names = sorted(k[0] for k in kids[id(st)])
+        decode = [k for k in names if k != "serve.admit"]
+        assert decode == ["serve.decode_step", "serve.emit", "serve.sample"], names
+        [sample] = [k for k in kids[id(st)] if k[0] == "serve.sample"]
+        assert sample[3] == {"rows": spec.serve.max_batch}
+        assert set(st[3]) == {"step", "active", "queued"}
+    for ad in admits:
+        rid = ad[3]["rid"]
+        n = len(prompts[rid])
+        assert ad[3] == {"rid": rid, "prompt_len": n,
+                         "bucket": session.engine.bucket_len(n)}
+        assert _parent(ad, spans)[0] == "serve.step"
+        assert [k[0] for k in kids[id(ad)]] == [
+            "serve.prefill", "serve.sample", "serve.insert"
+        ]
+        prefill, sample, _ = kids[id(ad)]
+        assert prefill[3] == {"bucket": ad[3]["bucket"]} and sample[3] == {"rows": 1}
+    assert sorted(a[3]["rid"] for a in admits) == list(range(len(prompts)))
+    emitted = sum(k[3]["done"] for k in spans if k[0] == "serve.emit")
+    assert emitted == len(prompts)
+
+
+def test_scheduler_gauges_only_at_submit_and_step_attrs_carry_the_rest():
+    from repro.api.spec import TelemetrySpec
+    from repro.telemetry import MemorySink
+
+    spec = dataclasses.replace(
+        tiny_spec(), telemetry=TelemetrySpec(enabled=True, sinks="memory"),
+    )
+    sess = serve(spec)
+    sess.generate(prompts_for(spec, n=3, seed=5), arrival_steps=[0, 0, 3])
+    [sink] = [s for s in sess.hub.sinks if isinstance(s, MemorySink)]
+    gauges = [e for e in sink.events if e["kind"] == "gauge"]
+    assert [e["name"] for e in gauges] == ["serve.queue_depth"] * 3
+    steps = [e for e in sink.events if e["kind"] == "span" and e["name"] == "serve.step"]
+    assert [e["attrs"]["step"] for e in steps] == list(range(len(steps)))
+    assert steps[0]["attrs"]["queued"] == 2 and steps[0]["attrs"]["active"] == 0
+
+
+def test_prefill_s_covers_the_wait_for_the_first_token(session, monkeypatch):
+    import time
+
+    real = type(session.engine).sample
+
+    def slow_first(self, logits, rids, steps):
+        if len(rids) == 1:
+            time.sleep(0.05)
+        return real(self, logits, rids, steps)
+
+    p = np.arange(1, 8, dtype=np.int32)
+    session.generate([p])  # compiles
+    monkeypatch.setattr(type(session.engine), "sample", slow_first)
+    [c] = session.run([Request(rid=3, tokens=p)])
+    assert c.prefill_s >= 0.05
+    assert 0 < c.decode_s

@@ -11,8 +11,9 @@ Pins, in order:
 - the Perfetto exporter: a 3-round async straggler run exports valid
   ``trace_event`` JSON with per-client tracks on both the wall and the
   virtual clock, monotone timestamps per track;
-- hub mechanics: a disabled hub emits nothing and hands out a no-op
-  span; ``sample_every`` drops only gauge/hist events off-cadence.
+- hub mechanics: a disabled hub emits nothing and its spans are the
+  profiler annotation alone; an enabled hub's span is a sink event and an
+  annotation; ``sample_every`` drops only gauge/hist events off-cadence.
 """
 import dataclasses
 import json
@@ -216,20 +217,75 @@ def test_perfetto_export_async_straggler(tmp_path):
 # ---------------------------------------------------------------------------
 
 
-def test_disabled_hub_is_noop():
+def _profiled(tmp_path, fn):
+    """Run ``fn`` under the profiler; the host events it recorded, as
+    {name: [stats dict]}."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    [path] = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    out.setdefault(e.name, []).append(dict(e.stats))
+    return out
+
+
+def test_disabled_hub_is_noop(tmp_path):
     sink = MemorySink()
     hub = TelemetryHub([sink], enabled=False)
-    with hub.span("x", round=0):
-        pass
-    hub.span_at("y", 0.0, 1.0)
+
+    def observe():
+        with hub.span("x", round=0, rows=7):
+            pass
+        hub.span_at("y", 0.0, 1.0)
+        hub.counter("c")
+        hub.gauge("g", 1.0)
+        hub.hist("h", 1.0)
+        hub.progress("hello")
+
+    host = _profiled(tmp_path, observe)
+    assert sink.events == []
+    # the span is the profiler's annotation alone, its attrs as stats
+    assert host["x"] == [{"round": 0, "rows": 7}]
+    assert not {"y", "c", "g", "h"} & set(host)
+    assert NULL_HUB.enabled is False
+
+
+def test_enabled_hub_span_is_a_sink_event_and_an_annotation(tmp_path):
+    sink = MemorySink()
+    hub = TelemetryHub([sink])
+
+    def observe():
+        with hub.span("serve.emit", step=3) as sp:
+            sp.set_metadata(done=2)
+
+    host = _profiled(tmp_path, observe)
+    [ev] = [e for e in sink.events if e["kind"] == "span"]
+    assert ev["name"] == "serve.emit" and ev["dur"] >= 0
+    assert ev["attrs"] == {"step": 3, "done": 2}
+    assert host["serve.emit"] == [{"step": 3, "done": 2}]
+
+
+def test_a_disabled_spec_keeps_progress_lines_only(capsys):
+    from repro.telemetry import hub_from_spec
+
+    hub = hub_from_spec(TelemetrySpec(enabled=False))
+    assert hub.enabled is False
     hub.counter("c")
     hub.gauge("g", 1.0)
-    hub.hist("h", 1.0)
-    hub.progress("hello")
-    assert sink.events == []
-    # the disabled span context manager is one cached object
-    assert hub.span("a") is hub.span("b")
-    assert NULL_HUB.enabled is False
+    with hub.span("s") as sp:
+        sp.set_metadata(done=1)
+    hub.progress("round 3 done")
+    assert capsys.readouterr().out == "round 3 done\n"
 
 
 def test_sample_every_drops_offcadence_gauges():

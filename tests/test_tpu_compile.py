@@ -11,6 +11,7 @@ The topology is described inside a fixture (never while a module is
 imported), and every test that needs it lives in this one file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +21,7 @@ from jax.sharding import SingleDeviceSharding
 import repro.kernels.ops as ops
 from repro.configs import get_config
 from repro.kernels.coeff_grad import atb
+from repro.kernels.flash_attention import flash_attention
 from repro.kernels.lowrank_matmul import avt, xus
 
 BF16 = jnp.bfloat16
@@ -143,3 +145,29 @@ def test_lowrank_apply_vjp_compiles(one_chip, monkeypatch, label, K, N, R):
     text = _compiled_text(fwd_bwd, *_factor_args(one_chip, label, K, N, R))
     # forward xus/avt plus the backward's xus ×4, avt and atb ×3
     assert text.count("tpu_custom_call") >= 8
+
+
+def _flash(q, k, v):
+    pos = jnp.arange(q.shape[1])
+    return flash_attention(q, k, v, q_positions=pos, kv_positions=pos)
+
+
+# (kernel, function, operands as (shape, dtype)): flash attention lowers
+# for the TPU only at one (batch, head) row, its position blocks being
+# (1, bq)
+NAMED = [
+    ("xus", xus, [((M, D), BF16), ((D, 256), BF16), ((256, 256), jnp.float32)]),
+    ("avt", avt, [((M, 256), BF16), ((D, 256), BF16)]),
+    ("atb", atb, [((M, D), BF16), ((M, 256), BF16)]),
+    ("flash_attention", _flash, [((1, 256, 1, 128), BF16)] * 3),
+]
+
+
+@pytest.mark.parametrize("name,fn,operands", NAMED, ids=[k[0] for k in NAMED])
+def test_kernel_name_is_in_the_compiled_hlo(one_chip, name, fn, operands):
+    """Each ``pallas_call`` names its kernel, so that the compiled program
+    (and a profiler trace of it) calls it by name."""
+    args = [_sds(one_chip, *shape, dtype=dt) for shape, dt in operands]
+    # under a lambda, so that the jitted function's own name is not the op's
+    text = _compiled_text(lambda *a: fn(*a), *args)
+    assert re.search(rf"%{name}(\.\d+)? = .*tpu_custom_call", text), name
