@@ -154,12 +154,12 @@ def cache_specs(cfg: ModelConfig, model, B: int, cache_len: int, mesh) -> Tuple[
         nd = len(s.shape)
         bax = None if shard_seq else batch_ax
         if "'k'" in name or "'v'" in name:
-            # (NB, B, S, Hkv, hd): prefer kv-head sharding; small-GQA archs
+            # (NB, B, Hkv, S, hd): prefer kv-head sharding; small-GQA archs
             # (kv < model size) shard head_dim instead; long_500k shards S.
-            kv_ax = fit(s.shape[3], "model")
+            kv_ax = fit(s.shape[2], "model")
             hd_ax = fit(s.shape[4], "model") if kv_ax is None else None
             seq_ax = batch_ax if shard_seq else None
-            return P(None, fit(s.shape[1], bax), seq_ax, kv_ax, hd_ax)
+            return P(None, fit(s.shape[1], bax), kv_ax, seq_ax, hd_ax)
         if "'S'" in name:  # rwkv state (NB, B, H, hd, hd)
             return P(None, fit(s.shape[1], bax), fit(s.shape[2], "model"), None, None)
         if "'h'" in name and nd == 4:  # mamba (NB, B, d_inner, N)

@@ -258,6 +258,24 @@ def _gqa_combine(p: Array, v: Array) -> Array:
     return o.reshape(B, Tq, H, v.shape[-1])
 
 
+def attention_mask(
+    q_positions: Array, kv_positions: Array, *, causal: bool,
+    sliding_window: int,
+) -> Array:
+    """Which keys each query may attend, from absolute positions.
+
+    Negative kv positions mark never-written cache slots; the broadcasting
+    form yields (Tq, Tk) for shared positions and (B, Tq, Tk) when either
+    side is per-slot."""
+    qpos, kpos = q_positions[..., :, None], kv_positions[..., None, :]
+    m = (kpos >= 0) & (qpos >= 0)
+    if causal:
+        m &= kpos <= qpos
+    if sliding_window:
+        m &= kpos > qpos - sliding_window
+    return m
+
+
 def attention(
     q: Array,
     k: Array,
@@ -299,20 +317,10 @@ def attention(
         if 0 < local_rows <= q_chunk:
             q_chunk = Tq
 
-    def mask_for(qpos, kpos):
-        # negative kv positions mark never-written cache slots; the
-        # broadcasting form yields (Tq, Tk) for shared positions and
-        # (B, Tq, Tk) when either side is per-slot
-        m = (kpos[..., None, :] >= 0) & (qpos[..., :, None] >= 0)
-        if causal:
-            m &= kpos[..., None, :] <= qpos[..., :, None]
-        if sliding_window:
-            m &= kpos[..., None, :] > qpos[..., :, None] - sliding_window
-        return m
-
     def block(qc, qpos):
         s = _gqa_scores(qc, k).astype(jnp.float32)  # (B,H,qc,Tk)
-        m = mask_for(qpos, kv_positions)
+        m = attention_mask(qpos, kv_positions, causal=causal,
+                           sliding_window=sliding_window)
         m = m[:, None] if m.ndim == 3 else m[None, None]
         s = jnp.where(m, s, -1e30)
         p = jax.nn.softmax(s, axis=-1).astype(v.dtype)

@@ -7,8 +7,10 @@ This preserves the true layer interleaving (e.g. Jamba's 7:1 mamba:attn)
 while keeping the lowered HLO one-superblock-sized — essential for CPU
 compile times of the 512-device dry-run.
 
-Serving: every mixer exposes a cache slice; the same scan threads cache
-slices through as scan ys, so decode is a single fused HLO too.
+Serving: the same scan carries the whole cache stacks (leading dim =
+superblocks) and each layer writes its new rows into them in place at its
+layer index, so decode is a single fused HLO too, and a step moves one row
+per sequence where scan xs/ys would copy every layer out and back.
 """
 from __future__ import annotations
 
@@ -18,6 +20,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.decode_attention import block_len, decode_attention
+from repro.kernels.ops import use_kernels_for
 from repro.models import sharding
 from repro.models.config import ModelConfig
 from repro.models.layers import (
@@ -25,6 +29,7 @@ from repro.models.layers import (
     apply_linear,
     apply_rope,
     attention,
+    attention_mask,
     rms_norm,
 )
 from repro.models.moe import build_moe, moe_block
@@ -114,17 +119,24 @@ def attn_mix(
     *,
     positions: Array,
     cache: Optional[dict],
+    layer: Optional[Array] = None,
     causal: bool = True,
     cross_kv: Optional[Tuple[Array, Array]] = None,
     use_rope: bool = True,
 ):
     """Self-attention (+ optional cached decode, + optional cross-attn block).
 
-    cache: {"k": (B,S,Hkv,hd), "v": ..., "idx": scalar int32} or None.
-    A *per-slot* cache carries ``idx`` of shape (B,) instead — one write
+    cache: the whole layer stack {"k": (NB,B,Hkv,S,hd), "v": ...,
+    "idx": (NB,) int32} or None; this block is layer ``layer`` (a traced
+    index) of it.  The new keys and values are written into the stack in
+    place and the attention reads layer ``layer`` from it, so a decode
+    step moves one row per sequence, not a copy of the layer (one new
+    token a sequence: the Pallas ``decode_attention`` wherever the kernel
+    policy allows it).  A
+    *per-slot* cache carries ``idx`` of shape (NB, B) instead — one write
     position per sequence (continuous batching admits requests into freed
     slots, so rows decode at different depths); ``positions`` is then
-    (B, T).  Returns (y, new_cache).
+    (B, T).  Returns (y, new_cache), new_cache the updated stack.
     """
     B, T, d = x.shape
     H, Hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
@@ -143,40 +155,63 @@ def attn_mix(
 
     new_cache = None
     if cache is not None:
-        S = cache["k"].shape[1]
+        kc, vc = cache["k"], cache["v"]
+        S = kc.shape[3]
         if cfg.sliding_window and S < cfg.sliding_window and S < 4096:
             raise ValueError("cache smaller than the attention window")
-        # ring/linear write at idx (mod cache length).  NOTE: a multi-token
-        # write (prefill) must not wrap: callers size the prefill cache at
-        # ≥ prompt length; decode writes are single-token and wrap freely.
-        idx = cache["idx"]
+        # ring/linear write at idx (mod cache length), in place in the
+        # layer stack.  NOTE: a multi-token write (prefill) must not wrap:
+        # callers size the prefill cache at ≥ prompt length; decode writes
+        # are single-token and wrap freely.
+        idx = jax.lax.dynamic_index_in_dim(cache["idx"], layer, 0, keepdims=False)
+        slot = (idx % S).astype(jnp.int32)
+        k, v = k.astype(kc.dtype), v.astype(vc.dtype)
         if idx.ndim:  # per-slot (B,): each row writes at its own position
-            slot = (idx % S).astype(jnp.int32)
-            write = lambda cb, xb, sb: jax.lax.dynamic_update_slice(
-                cb, xb, (sb, 0, 0)
-            )
-            ck = jax.vmap(write)(cache["k"], k.astype(cache["k"].dtype), slot)
-            cv = jax.vmap(write)(cache["v"], v.astype(cache["v"].dtype), slot)
+            # one index per (sequence, kv head, position): each write is a
+            # row of hd, so the stack keeps its head-major layout
+            rows = jnp.arange(B)[:, None, None]
+            heads = jnp.arange(Hkv)[None, :, None]
+            cols = (slot[:, None] + jnp.arange(T))[:, None, :]
+            ck = kc.at[layer, rows, heads, cols].set(k.swapaxes(1, 2))
+            cv = vc.at[layer, rows, heads, cols].set(v.swapaxes(1, 2))
             s_idx = jnp.arange(S, dtype=jnp.int32)[None, :]
             newest = idx.astype(jnp.int32)[:, None] + T - 1  # (B, 1)
-            kv_pos = newest - ((newest - s_idx) % S)  # (B, S)
         else:
-            slot = (idx % S).astype(jnp.int32)
-            ck = jax.lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype), (0, slot, 0, 0))
-            cv = jax.lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype), (0, slot, 0, 0))
-            # absolute position held by each slot: the newest p ≤ newest-
-            # written position with p % S == s; never written → negative.
+            at = (layer, 0, 0, slot, 0)
+            ck = jax.lax.dynamic_update_slice(kc, k.swapaxes(1, 2)[None], at)
+            cv = jax.lax.dynamic_update_slice(vc, v.swapaxes(1, 2)[None], at)
             s_idx = jnp.arange(S, dtype=jnp.int32)
             newest = idx.astype(jnp.int32) + T - 1
-            kv_pos = newest - ((newest - s_idx) % S)
+        # absolute position held by each slot: the newest p ≤ newest-
+        # written position with p % S == s; never written → negative.
+        kv_pos = newest - ((newest - s_idx) % S)
         kv_pos = jnp.where(kv_pos < 0, jnp.int32(-(10**9)), kv_pos)
-        y = attention(
-            q, ck, cv,
-            q_positions=positions, kv_positions=kv_pos,
-            causal=causal, sliding_window=cfg.sliding_window,
-            q_chunk=cfg.attn_q_chunk,
-        )
-        new_cache = {"k": ck, "v": cv, "idx": cache["idx"] + T}
+        # one new token a sequence: the kernel reads the layer from the
+        # stack where it lies; XLA would copy it out before its dot
+        use = T == 1 and block_len(S, Hkv, hd, kc.dtype.itemsize) > 0
+        use = use and use_kernels_for(cfg.kernels)
+        if use:
+            mask = attention_mask(positions, kv_pos, causal=causal,
+                                  sliding_window=cfg.sliding_window)
+            y = decode_attention(
+                q[:, 0], jnp.broadcast_to(mask.reshape(-1, S), (B, S)),
+                ck, cv, layer, interpret=use == "interpret",
+            )[:, None]
+        else:
+            layer_of = lambda c: jax.lax.dynamic_index_in_dim(
+                c, layer, 0, keepdims=False).swapaxes(1, 2)
+            y = attention(
+                q, layer_of(ck), layer_of(cv),
+                q_positions=positions, kv_positions=kv_pos,
+                causal=causal, sliding_window=cfg.sliding_window,
+                q_chunk=cfg.attn_q_chunk,
+            )
+        new_cache = {
+            "k": ck, "v": cv,
+            "idx": jax.lax.dynamic_update_index_in_dim(
+                cache["idx"], idx + T, layer, 0
+            ),
+        }
     else:
         kv_pos = positions
         y = attention(
@@ -225,23 +260,33 @@ def block_apply(
     *,
     positions: Array,
     cache: Optional[dict],
+    layer: Optional[Array] = None,
     causal: bool = True,
     cross_kv=None,
     use_rope: bool = True,
 ):
     """One (mixer + FFN/MoE) block with pre-norm residuals.
 
-    Returns (x, new_cache, aux_loss)."""
+    ``cache``: the mixer's whole layer stack (leading dim = superblocks),
+    of which this block is layer ``layer``.  Returns (x, new_cache,
+    aux_loss), new_cache the updated stack."""
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
     if kind == "attn":
         mix_out, new_cache = attn_mix(
-            p["attn"], h, cfg, positions=positions, cache=cache,
+            p["attn"], h, cfg, positions=positions, cache=cache, layer=layer,
             causal=causal, cross_kv=cross_kv, use_rope=use_rope,
         )
-    elif kind == "mamba":
-        mix_out, new_cache = mamba_mix(p["mamba"], h, cfg, state=cache)
-    elif kind == "rwkv":
-        mix_out, new_cache = rwkv_mix(p["rwkv"], h, cfg, state=cache)
+    elif kind in ("mamba", "rwkv"):
+        mix = mamba_mix if kind == "mamba" else rwkv_mix
+        state = None if cache is None else jax.tree.map(
+            lambda s: jax.lax.dynamic_index_in_dim(s, layer, 0, keepdims=False),
+            cache,
+        )
+        mix_out, new_state = mix(p[kind], h, cfg, state=state)
+        new_cache = None if cache is None else jax.tree.map(
+            lambda s, n: jax.lax.dynamic_update_index_in_dim(s, n, layer, 0),
+            cache, new_state,
+        )
     else:
         raise ValueError(kind)
     x = x + mix_out
@@ -264,7 +309,9 @@ def init_cache_stack(
 ) -> dict:
     """Per-position cache stacks (leading dim = superblocks).
 
-    ``per_slot=True`` makes the attention write index a vector over the
+    Attention keys and values are head-major, ``(NB, batch, Hkv, S, hd)``:
+    each kv head's positions are contiguous, as the decode kernel reads
+    them.  ``per_slot=True`` makes the attention write index a vector over the
     batch — (NB, batch) instead of (NB,) — so a serving engine can hold
     sequences at different positions in one decode batch."""
     NB = cfg.superblocks
@@ -274,8 +321,8 @@ def init_cache_stack(
     for i, kind in enumerate(cfg.block_pattern):
         if kind == "attn":
             c = {
-                "k": jnp.zeros((NB, batch, cache_len, Hkv, hd), dtype),
-                "v": jnp.zeros((NB, batch, cache_len, Hkv, hd), dtype),
+                "k": jnp.zeros((NB, batch, Hkv, cache_len, hd), dtype),
+                "v": jnp.zeros((NB, batch, Hkv, cache_len, hd), dtype),
                 "idx": jnp.zeros(idx_shape, jnp.int32),
             }
         elif kind == "mamba":
@@ -307,23 +354,27 @@ def stack_apply(
     pattern: Optional[Tuple[str, ...]] = None,
     moe_positions: Optional[Tuple[bool, ...]] = None,
 ):
-    """Scan the superblock stack.  blocks/caches: dicts of stacked params.
+    """Scan the superblock stack.  blocks/caches: dicts of stacked params
+    and cache stacks.
 
-    Returns (x, new_caches, total_aux)."""
+    The caches are the scan's carry, not its xs/ys: each layer writes its
+    new entries into the stacks in place at its layer index, and attention
+    reads the layer from them.  ``caches=None`` (training, the encoder)
+    scans the blocks alone.  Returns (x, new_caches, total_aux)."""
     pattern = pattern or cfg.block_pattern
 
     # remat per *layer*, not per superblock: a superblock backward would
     # otherwise hold every member layer's recomputed internals live at once
     # (ruinous for Jamba's 7 Mamba layers per superblock).
     def one_block(kind):
-        def f(p_i, h, c_i):
+        def f(p_i, h, c_i, layer):
             # pin the layout of the residual stream at every layer: the
             # checkpoint below saves this tensor, and an unpinned save point
             # is replicated (72 × full-T·d f32 on jamba — dozens of GiB)
             h = sharding.shard(h, "batch", "seq", None)
             return block_apply(
                 p_i, kind, h, cfg,
-                positions=positions, cache=c_i, causal=causal,
+                positions=positions, cache=c_i, layer=layer, causal=causal,
                 cross_kv=cross_kv, use_rope=use_rope,
             )
 
@@ -333,29 +384,30 @@ def stack_apply(
 
     block_fns = [one_block(kind) for kind in pattern]
 
-    def superblock(carry, xs):
-        h, aux = carry
+    def superblock(h, aux, c_sb, p_sb, layer):
         h = sharding.shard(h, "batch", "seq", None)
-        p_sb, c_sb = xs
-        new_c = {}
-        for i, _kind in enumerate(pattern):
-            c_i = c_sb.get(f"pos{i}") if c_sb is not None else None
-            h, nc, a = block_fns[i](p_sb[f"pos{i}"], h, c_i)
-            if nc is not None:
-                new_c[f"pos{i}"] = nc
+        for i in range(len(pattern)):
+            key = f"pos{i}"
+            c_i = None if c_sb is None else c_sb[key]
+            h, nc, a = block_fns[i](p_sb[key], h, c_i, layer)
+            if c_sb is not None:
+                c_sb = {**c_sb, key: nc}
             aux = aux + a
-        return (h, aux), (new_c if new_c else None)
+        return h, aux, c_sb
 
-    body = superblock
-
+    carry = (x, jnp.zeros((), jnp.float32))
     if caches is None:
         (h, aux), _ = jax.lax.scan(
-            lambda c, p_sb: (body(c, (p_sb, None))[0], ()),
-            (x, jnp.zeros((), jnp.float32)),
-            blocks,
+            lambda c, p_sb: (superblock(*c, None, p_sb, None)[:2], ()),
+            carry, blocks,
         )
         return h, None, aux
-    (h, aux), new_caches = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)), (blocks, caches)
+    # the cache stacks ride in the carry, indexed by the layer counter:
+    # each layer writes its new rows in place (as scan xs/ys, every layer
+    # would be sliced out of the stack and written back whole)
+    n_blocks = jax.tree.leaves(caches)[0].shape[0]
+    (h, aux, new_caches), _ = jax.lax.scan(
+        lambda c, xs: (superblock(*c, *xs), ()),
+        carry + (caches,), (blocks, jnp.arange(n_blocks)),
     )
     return h, new_caches, aux

@@ -10,10 +10,22 @@ discipline (the jit-invariant the lint's trace auditor pins):
   ``last_index`` reads the true last-token logits, so bucketing changes
   compilation count, never tokens;
 - **one insert executable** — copies a B=1 prefill cache into slot ``i``
-  of the per-slot batch state (slot and true length are traced scalars);
+  of the per-slot batch state (slot and true length are traced scalars).
+  It *consumes* the state it is given (donated): the slot's rows are
+  written in place, and the caller rebinds the state to the result;
 - **one decode executable** at the fixed ``(max_batch, cache_len)`` shape —
   every step decodes the full slot array; inactive slots carry garbage
-  rows that never escape (the scheduler ignores them).
+  rows that never escape (the scheduler ignores them).  Inside it the
+  cache is written in place, layer by layer; its input state is *not*
+  donated, so it stays readable after the step (the benchmark's planted
+  fault of a step that returns its input state relies on that), at the
+  price of one copy of the cache into the step's output.
+
+On its first call each of the two compiles ahead of time, and puts
+``serve.<insert|decode_step>.aliased_bytes`` and ``.temp_bytes`` (its
+compiled ``memory_analysis()``: the bytes of its inputs its outputs
+reuse, and its scratch) in the hub as gauges and on stderr; the jitted
+call reuses that executable.
 
 Params may arrive quantized (`repro.serve.quantize`); dequantization runs
 *inside* each executable so only the compressed buffers stay resident.
@@ -35,6 +47,7 @@ to the host, so it is where the host waits on the device.
 from __future__ import annotations
 
 import math
+import sys
 from typing import Dict, Optional
 
 import jax
@@ -147,7 +160,8 @@ class ServeEngine:
             return model.serve_step(dequantize_params(p), state, tokens)
 
         self._step_fn = jax.jit(step)
-        self._insert_fn = jax.jit(_insert_cache)
+        self._insert_fn = jax.jit(_insert_cache, donate_argnums=(0,))
+        self._compiled = set()  # names whose memory analysis is out
         self._prefill_fns: Dict[int, object] = {}
         self._base_key = jax.random.PRNGKey(self.seed)
 
@@ -213,22 +227,45 @@ class ServeEngine:
         with self.hub.span("serve.prefill", bucket=lb):
             return fn(self.params, jnp.asarray(tokens), jnp.int32(length - 1))
 
+    def _call(self, name: str, fn, *args):
+        """``fn(*args)``; on the first call, compiled ahead so that its
+        memory analysis can be reported (the call reuses the executable)."""
+        if name not in self._compiled:
+            self._compiled.add(name)
+            mem = fn.lower(*args).compile().memory_analysis()
+            if mem is not None:
+                got = {"aliased_bytes": mem.alias_size_in_bytes,
+                       "temp_bytes": mem.temp_size_in_bytes}
+                for k, v in got.items():
+                    self.hub.gauge(f"{name}.{k}", v)
+                print(f"{name}: compiled, "
+                      + ", ".join(f"{k} {v}" for k, v in got.items()),
+                      file=sys.stderr, flush=True)
+        return fn(*args)
+
     def insert(self, state, cache, slot: int, length: int):
-        """Graft a B=1 prefill ``cache`` into ``state`` row ``slot``."""
+        """Graft a B=1 prefill ``cache`` into ``state`` row ``slot``.
+
+        Consumes ``state``: its buffers are donated to the result, which
+        the caller must use in its place."""
         with self.hub.span("serve.insert"):
-            return self._insert_fn(
-                state, cache, jnp.int32(slot), jnp.int32(length)
+            return self._call(
+                "serve.insert", self._insert_fn,
+                state, cache, jnp.int32(slot), jnp.int32(length),
             )
 
     # ------------------------------------------------------------ decode
 
     def step(self, state, last_tokens):
-        """One decode step over all slots: (B,) tokens → (logits, state)."""
+        """One decode step over all slots: (B,) tokens → (logits, state).
+        ``state`` stays readable: the step does not consume it."""
         with self.hub.span("serve.decode_step"):
             tokens = jnp.asarray(last_tokens, jnp.int32).reshape(
                 self.max_batch, 1
             )
-            return self._step_fn(self.params, state, tokens)
+            return self._call(
+                "serve.decode_step", self._step_fn, self.params, state, tokens
+            )
 
     def sample(self, logits, rids, steps) -> np.ndarray:
         """Batching-invariant sampling: greedy at temperature 0, else a

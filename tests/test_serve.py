@@ -365,6 +365,51 @@ def test_prompt_bucketing_is_transparent(session):
     assert eng.num_executables() == 4  # 2 buckets + insert + step
 
 
+def test_insert_consumes_its_state_and_the_step_does_not():
+    """The insert writes the slot into its donated state in place; the
+    decode step leaves its input state readable (the benchmark's planted
+    fault of a step returning its input state relies on that).  Each
+    compiles once, and reports its compiled memory."""
+    from repro.api.spec import TelemetrySpec
+    from repro.telemetry import MemorySink
+
+    spec = dataclasses.replace(
+        tiny_spec(), telemetry=TelemetrySpec(enabled=True, sinks="memory"),
+    )
+    eng = serve(spec).engine
+    compiles = []
+
+    def listen(event, duration, fun_name="", **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(fun_name)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        state = eng.new_state()
+        kv = [x for x in jax.tree.leaves(state["stack"]) if x.ndim == 5]
+        kv_bytes = sum(x.nbytes for x in kv)
+        prompt = np.arange(1, 6, dtype=np.int32)
+        for slot in (1, 2):
+            _, cache = eng.prefill(prompt)
+            old, state = state, eng.insert(state, cache, slot, prompt.size)
+            assert all(
+                x.is_deleted() for x in jax.tree.leaves(old["stack"]) if x.ndim == 5
+            )
+        tokens = np.full(eng.max_batch, 7, np.int32)
+        first, _ = eng.step(state, tokens)
+        again, _ = eng.step(state, tokens)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    np.testing.assert_array_equal(np.asarray(first), np.asarray(again))
+    # at most once: an executable compiled by an earlier engine is reused
+    assert compiles.count("jit(_insert_cache)") <= 1
+    assert compiles.count("jit(step)") <= 1
+    [sink] = [s for s in eng.hub.sinks if isinstance(s, MemorySink)]
+    got = {e["name"]: e["value"] for e in sink.events if e["kind"] == "gauge"}
+    assert got["serve.insert.aliased_bytes"] >= kv_bytes > 0
+    assert got["serve.decode_step.aliased_bytes"] == 0
+
+
 def test_prompt_too_long_rejected(session):
     with pytest.raises(ValueError, match="exceeds max_prompt"):
         session.engine.prefill(np.arange(99, dtype=np.int32))
@@ -520,8 +565,13 @@ def test_scheduler_gauges_only_at_submit_and_step_attrs_carry_the_rest():
     sess = serve(spec)
     sess.generate(prompts_for(spec, n=3, seed=5), arrival_steps=[0, 0, 3])
     [sink] = [s for s in sess.hub.sinks if isinstance(s, MemorySink)]
-    gauges = [e for e in sink.events if e["kind"] == "gauge"]
-    assert [e["name"] for e in gauges] == ["serve.queue_depth"] * 3
+    gauges = [e["name"] for e in sink.events if e["kind"] == "gauge"]
+    # besides the scheduler's, the engine's two executables report their
+    # compiled memory once each, at their first call
+    memory = [f"serve.{x}.{k}" for x in ("insert", "decode_step")
+              for k in ("aliased_bytes", "temp_bytes")]
+    assert [g for g in gauges if g not in memory] == ["serve.queue_depth"] * 3
+    assert sorted(g for g in gauges if g in memory) == sorted(memory)
     steps = [e for e in sink.events if e["kind"] == "span" and e["name"] == "serve.step"]
     assert [e["attrs"]["step"] for e in steps] == list(range(len(steps)))
     assert steps[0]["attrs"]["queued"] == 2 and steps[0]["attrs"]["active"] == 0

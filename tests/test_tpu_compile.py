@@ -171,3 +171,65 @@ def test_kernel_name_is_in_the_compiled_hlo(one_chip, name, fn, operands):
     # under a lambda, so that the jitted function's own name is not the op's
     text = _compiled_text(lambda *a: fn(*a), *args)
     assert re.search(rf"%{name}(\.\d+)? = .*tpu_custom_call", text), name
+
+
+@pytest.mark.parametrize("S", [2048, 4096])
+def test_decode_step_writes_the_cache_in_place(one_chip, monkeypatch, S):
+    """The serving engine's decode step at the chat cell's shapes (Qwen2-7B,
+    32 slots of 2048 positions; and of 4096, read in two blocks a slot):
+    each layer's keys and values are read by
+    the decode-attention kernel where they lie in the head-major stack,
+    and written in place.  The one copy of the cache left is the step's
+    input into its output, the input not being donated."""
+    from repro.api import ModelSpec
+    from repro.api.tasks import lm_model_config
+    from repro.models import build_model
+    from repro.serve.quantize import dequantize_params
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    model = build_model(lm_model_config(ModelSpec(arch="qwen2_7b", kernels="auto")))
+    B = 32
+    place = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+    params = jax.tree.map(place, jax.eval_shape(
+        lambda k: model.init(k)[0], jax.random.PRNGKey(0)))
+    state = jax.tree.map(place, jax.eval_shape(
+        lambda: model.init_cache(None, B, S, per_slot=True)))
+    step = lambda p, st, t: model.serve_step(dequantize_params(p), st, t)
+    text = jax.jit(step).lower(
+        params, state, _sds(one_chip, B, 1, dtype=jnp.int32)
+    ).compile().as_text()
+    assert re.search(r"%decode_attention(\.\d+)? = .*tpu_custom_call", text)
+    NB, Hkv, hd = QWEN.num_layers, QWEN.num_kv_heads, QWEN.hd
+    stack = re.escape(f"bf16[{NB},{B},{Hkv},{S},{hd}]")
+    # no slice of a layer is made, in either order of its axes
+    for layer in (f"{B},{Hkv},{S},{hd}]", f"{B},{S},{Hkv},{hd}]"):
+        assert f"bf16[{layer}" not in text and f"bf16[1,{layer}" not in text
+    made = re.findall(rf"= {stack}\{{([^}}]*)\}} ([a-z-]+)\(", text)
+    # the stacks keep the head-major layout the kernel reads (a relayout
+    # would be a copy of the whole stack in every layer)...
+    assert made and all(layout.startswith("4,3,2,1,0:") for layout, _ in made)
+    ops_ = sorted(op for _, op in made)
+    # ...are made only by the in-place scatter of each layer's rows (in
+    # its fusion), and copied once each: the step's input into its output
+    assert ops_.count("copy") == 2  # keys and values
+    assert set(ops_) <= {"parameter", "get-tuple-element", "copy", "fusion", "scatter"}
+    assert ops_.count("fusion") == ops_.count("scatter") == 2
+
+
+@pytest.mark.parametrize("Hkv,G,hd,S", [
+    (4, 7, 128, 2048),  # Qwen2-7B: one block a slot
+    (8, 8, 128, 4096),  # 64 query heads on 8 kv heads: 1024-position blocks
+    (8, 4, 64, 2048),  # half-width heads
+    (32, 1, 128, 1024),  # no grouping
+])
+def test_decode_attention_compiles(one_chip, Hkv, G, hd, S):
+    from repro.kernels.decode_attention import block_len, decode_attention
+
+    NB, B = 4, 16
+    assert block_len(S, Hkv, hd, 2) > 0
+    _compiled_text(
+        lambda q, m, k, v, l: decode_attention(q, m, k, v, l),
+        _sds(one_chip, B, Hkv * G, hd), _sds(one_chip, B, S, dtype=jnp.bool_),
+        _sds(one_chip, NB, B, Hkv, S, hd), _sds(one_chip, NB, B, Hkv, S, hd),
+        _sds(one_chip, dtype=jnp.int32),
+    )
