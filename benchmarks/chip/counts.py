@@ -3,9 +3,9 @@ chip's published peaks.
 
 Whatever implements the work, these counts stay as they are: a later
 change that replaces a kernel leaves them unchanged.  Shapes come from a
-configuration file (``layout.py``).  :func:`decode_step_cost` gives the
-FLOPs and bytes one decode step requires: the factors once, the KV cache
-up to the positions read.
+configuration file; what one decode step requires is counted by the
+configuration's architecture file (``harness.architecture``), from the
+per-factor counts here.
 """
 from __future__ import annotations
 
@@ -13,7 +13,8 @@ import json
 from pathlib import Path
 from typing import List
 
-from benchmarks.chip import layout
+from benchmarks.chip import harness
+from benchmarks.chip.layout import Linear
 
 PEAKS_FILE = Path(__file__).resolve().parent / "peaks.json"
 
@@ -29,27 +30,27 @@ def peaks(device_kind: str, path: Path = PEAKS_FILE) -> dict:
     return table[device_kind]
 
 
-def decode_step_cost(cfg: dict, contexts: List[int], itemsize: int = 2):
-    """(flops, bytes) one decode step requires for sequences whose KV
-    holds ``contexts[i]`` positions (the new token's included).
-
-    Every factor is read once for the batch; each sequence reads its own
-    keys and values up to its position.  The embedding reads one row of U
-    per sequence.
-    """
-    B = len(contexts)
-    Hkv, hd, L = cfg["num_key_value_heads"], layout.head_dim(cfg), cfg["num_hidden_layers"]
-    H = cfg["num_attention_heads"]
-    flops, nbytes = 0, 0
-    for lin in layout.linears(cfg):
-        n = max(lin.layers, 1)
-        if lin.path == "embed":
-            flops += n * B * 2 * (lin.r * lin.r + lin.r * lin.n_out)
-            nbytes += n * itemsize * (B * lin.r + lin.r * lin.r + lin.n_out * lin.r)
-            continue
-        flops += n * B * 2 * (lin.n_in * lin.r + lin.r * lin.r + lin.r * lin.n_out)
-        nbytes += n * itemsize * (lin.n_in * lin.r + lin.r * lin.r + lin.n_out * lin.r)
-    ctx = sum(contexts)
-    flops += L * 2 * (2 * hd * H * ctx)
-    nbytes += L * itemsize * 2 * Hkv * hd * ctx
+def factor_cost(lin: Linear, rows: int, itemsize: int) -> tuple:
+    """(flops, bytes) of ``((x U) S) Vᵀ`` on ``rows`` rows, in every layer
+    of the stack: each factor is read once for all rows."""
+    n = max(lin.layers, 1)
+    flops = n * rows * 2 * (lin.n_in * lin.r + lin.r * lin.r + lin.r * lin.n_out)
+    nbytes = n * itemsize * (lin.n_in * lin.r + lin.r * lin.r + lin.n_out * lin.r)
     return flops, nbytes
+
+
+def embed_cost(lin: Linear, rows: int, itemsize: int) -> tuple:
+    """(flops, bytes) of the embedding rows ``U[t] S Vᵀ`` of ``rows``
+    tokens: one row of U per token, S and V once."""
+    n = max(lin.layers, 1)
+    flops = n * rows * 2 * (lin.r * lin.r + lin.r * lin.n_out)
+    nbytes = n * itemsize * (rows * lin.r + lin.r * lin.r + lin.n_out * lin.r)
+    return flops, nbytes
+
+
+def decode_step_cost(cfg: dict, contexts: List[int], itemsize: int = 2,
+                     root: Path = harness.ROOT) -> tuple:
+    """(flops, bytes) one decode step requires for sequences whose state
+    holds ``contexts[i]`` positions (the new token's included), as
+    ``cfg``'s architecture file (found under ``root``) counts them."""
+    return harness.architecture(cfg, root).decode_step_cost(cfg, contexts, itemsize)

@@ -2,14 +2,35 @@
 
 A benchmark tree (``benchmarks/chip`` by default, any directory in tests)
 holds ``workloads/<cell>.json``, ``configs/<config>.json``,
-``traffic/<mix>.json``, ``traffic/<kind>.py`` and ``metrics/<name>.py``;
-``BENCHMARK.json`` says which end-to-end and per-layer metrics each cell
-reports.  Adding a cell, a configuration, a mix or a per-layer metric
-adds files and touches no code.
+``traffic/<mix>.json``, ``traffic/<kind>.py``, ``metrics/<name>.py`` and
+``references/<architecture>.py``; ``BENCHMARK.json`` says which
+end-to-end and per-layer metrics each cell reports.  Adding a cell, a
+configuration, a mix, a per-layer metric or an architecture adds files
+and touches no code.
+
+A configuration's ``architecture`` names its architecture file
+(:func:`architecture`), which holds everything the benchmark knows of the
+model's shapes and math (module-level functions, each taking the
+configuration as read):
+
+- ``linears(cfg)``: the factorized weights, ``layout.Linear``s in the
+  order their seeds are drawn;
+- ``leaves(cfg)``: every other leaf as (path, shape, init), where init is
+  a number or a function of (key, shape) that draws from the seed;
+- ``program_fields(cfg)``: the program's model-config fields (dotted
+  attribute paths) that must equal the file's, beside the generic checks
+  of ``program.check_config``;
+- ``program_path(names)``: the benchmark path of a leaf of the program's
+  parameter pytree, from the pytree's key names;
+- ``logits(cfg, w, inputs, mode)``: the plain reference's float32 logits
+  for token ids, its products in ``mode`` (``references/arith.py``);
+- ``decode_step_cost(cfg, contexts, itemsize)``: the (FLOPs, bytes) one
+  decode step requires (``counts.py`` has per-factor helpers).
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import math
@@ -40,6 +61,22 @@ def load_module(path: Path, name: str) -> ModuleType:
     return mod
 
 
+@functools.lru_cache(maxsize=None)
+def _architecture_file(path: Path, name: str) -> ModuleType:
+    return load_module(path, name)
+
+
+def architecture(cfg: dict, root: Path = ROOT) -> ModuleType:
+    """The architecture file that ``cfg["architecture"]`` names, loaded
+    once per path: ``<root>/references/<architecture>.py``."""
+    name = cfg["architecture"]
+    path = Path(root) / "references" / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(
+            f"the configuration's architecture {name!r} has no file: {path}")
+    return _architecture_file(path, name)
+
+
 @dataclasses.dataclass
 class Cell:
     """One workload with everything it names, loaded."""
@@ -50,6 +87,7 @@ class Cell:
     traffic: dict
     end_to_end: List[dict]
     per_layer: List[dict]
+    root: Path = ROOT  # the tree its architecture file is found in
 
 
 class Bench:
@@ -69,7 +107,7 @@ class Bench:
             m for m in self.manifest["per_layer"]
             if (name in m["workloads"] if "workloads" in m else m["moves"] in names)
         ]
-        return Cell(name, wl, cfg, mix, e2e, per)
+        return Cell(name, wl, cfg, mix, e2e, per, self.root)
 
     def kind(self, kind: str) -> ModuleType:
         return load_module(self.root / "traffic" / f"{kind}.py", kind)

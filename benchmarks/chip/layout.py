@@ -1,14 +1,17 @@
-"""Shapes of a dense decoder's factorized weights, from a configuration file.
+"""Generic helpers for the shapes of a model's factorized weights.
 
 The benchmark's weight generator, its plain reference and its FLOP and
-byte counts all read the model's shapes from here, never from the
-program.  Paths name parameters as ``a/b/c`` strings; block parameters
-carry a leading layer axis.
+byte counts read a model's shapes from its architecture file
+(``references/<architecture>.py``, found by ``harness.architecture``),
+never from the program.  That file lists the factorized weights as
+:class:`Linear` s (built with :func:`factorized`) and every other leaf as
+(path, shape, init); see ``harness.py`` for all it provides.  Paths name
+parameters as ``a/b/c`` strings; block parameters carry a leading layer
+axis.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import List
 
 
 @dataclasses.dataclass(frozen=True)
@@ -41,46 +44,13 @@ def r_max(cfg: dict, n_in: int, n_out: int) -> int:
     return min(r, max(small // 2, 1))
 
 
-def linears(cfg: dict) -> List[Linear]:
-    """Every factorized weight of the model, embedding and head included."""
-    d, dff = cfg["hidden_size"], cfg["intermediate_size"]
-    H, Hkv, hd = cfg["num_attention_heads"], cfg["num_key_value_heads"], head_dim(cfg)
-    V, L = cfg["vocab_size"], cfg["num_hidden_layers"]
-    shapes = [
-        ("embed", V, d, 0),
-        ("lm_head", d, V, 0),
-        ("blocks/attn/q", d, H * hd, L),
-        ("blocks/attn/k", d, Hkv * hd, L),
-        ("blocks/attn/v", d, Hkv * hd, L),
-        ("blocks/attn/o", H * hd, d, L),
-        ("blocks/mlp/gate", d, dff, L),
-        ("blocks/mlp/up", d, dff, L),
-        ("blocks/mlp/down", dff, d, L),
-    ]
-    out = []
-    for path, n_in, n_out, layers in shapes:
-        if min(n_in, n_out) < cfg["lowrank"]["min_dim"]:
-            raise ValueError(
-                f"{path}: {n_in}x{n_out} would stay dense; the benchmark's "
-                f"reference covers factorized weights only"
-            )
-        out.append(Linear(path, n_in, n_out, r_max(cfg, n_in, n_out), layers))
-    return out
-
-
-def dense_leaves(cfg: dict) -> List[tuple]:
-    """(path, shape, init value) of the dense leaves: norm scales, QKV bias."""
-    d, L = cfg["hidden_size"], cfg["num_hidden_layers"]
-    H, Hkv, hd = cfg["num_attention_heads"], cfg["num_key_value_heads"], head_dim(cfg)
-    out = [
-        ("final_norm", (d,), 1.0),
-        ("blocks/ln1", (L, d), 1.0),
-        ("blocks/ln2", (L, d), 1.0),
-    ]
-    if cfg["qkv_bias"]:
-        out += [
-            ("blocks/attn/q_b", (L, H * hd), 0.0),
-            ("blocks/attn/k_b", (L, Hkv * hd), 0.0),
-            ("blocks/attn/v_b", (L, Hkv * hd), 0.0),
-        ]
-    return out
+def factorized(cfg: dict, path: str, n_in: int, n_out: int, layers: int = 0) -> Linear:
+    """The :class:`Linear` at ``path``, refused where the program's policy
+    (``lowrank.min_dim``) would keep the matrix dense: such a matrix is
+    one of the architecture's other leaves."""
+    if min(n_in, n_out) < cfg["lowrank"]["min_dim"]:
+        raise ValueError(
+            f"{path}: {n_in}x{n_out} would stay dense; list it among the "
+            f"architecture's other leaves"
+        )
+    return Linear(path, n_in, n_out, r_max(cfg, n_in, n_out), layers)

@@ -101,10 +101,11 @@ def open_server(cell: harness.Cell, seed: int):
         ),
     )
     mc = lm_model_config(spec.model)
-    check_config(cfg, mc)
+    check_config(cfg, mc, cell.root)
     model = build_model(mc)
     like = jax.eval_shape(lambda k: model.init(k)[0], jax.random.PRNGKey(0))
-    return serve(spec, params=program_params(weights.generate(cfg, seed), like))
+    flat = weights.generate(cfg, seed, cell.root)
+    return serve(spec, params=program_params(flat, like, cfg, cell.root))
 
 
 def warm(sess, mix: dict) -> None:
@@ -285,22 +286,22 @@ def sample(log: Log, seed: int, want_tokens: int) -> list:
 def logit_gaps(cell: harness.Cell, seed: int, seqs: list, mode: str = "float32",
                rows: int = 2) -> list:
     """For each (prompt, served tokens): per served token, how far its
-    logit in the float32 reference lies below the reference's best.  With
+    logit in the float32 reference (the logits of the configuration's
+    architecture file) lies below the reference's best.  With
     ``mode`` lower than float32, the token judged is the one that the
     reference in that precision puts first (the control).  Sequences run
     ``rows`` at a time, padded to the longest a slot can hold."""
     import jax
     import jax.numpy as jnp
 
-    from benchmarks.chip.references import dense_lm
-
     cfg = cell.config
-    w = {k: v for k, v in weights.generate(cfg, seed).items() if not k.endswith("/rank")}
+    arch = harness.architecture(cfg, cell.root)
+    w = {k: v for k, v in weights.generate(cfg, seed, cell.root).items()
+         if not k.endswith("/rank")}
     T = cell.traffic["max_prompt"] + cell.traffic["max_new_tokens"]
 
     def logits_fn(mode_):
-        return jax.jit(lambda w_, x: dense_lm.linear(
-            w_, "lm_head", dense_lm.hidden(cfg, w_, x, mode_), mode_))
+        return jax.jit(lambda w_, x: arch.logits(cfg, w_, x, mode_))
 
     ref = logits_fn("float32")
     low = logits_fn(mode) if mode != "float32" else None

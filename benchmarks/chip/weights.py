@@ -1,20 +1,23 @@
-"""Seeded weights for a dense decoder, made on the device in one jitted call.
+"""Seeded weights for a model, made on the device in one jitted call.
 
 The program gets these weights (cast into its own pytree by the traffic
 driver) and the plain reference gets the very same arrays, so neither
-side's weights come from the program.  The scheme follows FeDLRT's
-initialisation: orthonormal bases, a diagonal coefficient with a decaying
-He-scaled spectrum at full rank, unit norm scales and zero biases.
+side's weights come from the program.  The configuration's architecture
+file lists the leaves (``harness.architecture``).  Factorized weights
+follow FeDLRT's initialisation: orthonormal bases, a diagonal coefficient
+with a decaying He-scaled spectrum at full rank; every other leaf is a
+constant or drawn by the architecture's own init from the seed.
 """
 from __future__ import annotations
 
 import functools
 import json
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 
-from benchmarks.chip import layout
+from benchmarks.chip import harness
 
 HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -40,10 +43,11 @@ def orthonormal(key, shape):
     return a
 
 
-def _generate(cfg: dict, key):
+def _generate(cfg: dict, arch, key):
     dtype, basis = jnp.dtype(cfg["param_dtype"]), jnp.dtype(cfg["basis_dtype"])
     out = {}
-    for i, lin in enumerate(layout.linears(cfg)):
+    linears = arch.linears(cfg)
+    for i, lin in enumerate(linears):
         ku, kv = jax.random.split(jax.random.fold_in(key, i))
         r = lin.r
         scale = (2.0 * lin.n_out / r) ** 0.5
@@ -54,19 +58,25 @@ def _generate(cfg: dict, key):
             jnp.diag(sigma), lin.stack + (r, r)
         ).astype(dtype)
         out[lin.path + "/rank"] = jnp.full(lin.stack, float(r), jnp.float32)
-    for path, shape, value in layout.dense_leaves(cfg):
-        out[path] = jnp.full(shape, value, dtype)
+    # a drawn leaf's key is its place in the list, counted on from the
+    # factors': the factors' keys are those of an architecture without it
+    for j, (path, shape, init) in enumerate(arch.leaves(cfg)):
+        if callable(init):
+            out[path] = init(jax.random.fold_in(key, len(linears) + j), shape).astype(dtype)
+        else:
+            out[path] = jnp.full(shape, init, dtype)
     return out
 
 
 @functools.lru_cache(maxsize=8)
-def _generator(cfg_json: str):
+def _generator(cfg_json: str, root: str):
     cfg = json.loads(cfg_json)
-    return jax.jit(functools.partial(_generate, cfg))
+    return jax.jit(functools.partial(_generate, cfg, harness.architecture(cfg, Path(root))))
 
 
-def generate(cfg: dict, seed: int) -> dict:
-    """``{path: array}`` of every weight: bases in the configuration's
+def generate(cfg: dict, seed: int, root: Path = harness.ROOT) -> dict:
+    """``{path: array}`` of every weight of ``cfg``'s architecture (its
+    file found under ``root``): bases in the configuration's
     ``basis_dtype``, everything else in its ``param_dtype``."""
     with jax.default_matmul_precision("highest"):
-        return _generator(json.dumps(cfg, sort_keys=True))(seed_key(seed))
+        return _generator(json.dumps(cfg, sort_keys=True), str(root))(seed_key(seed))

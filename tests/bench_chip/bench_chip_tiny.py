@@ -59,6 +59,21 @@ SERVE_MIX = {
 SERVE_LIMITS = {"logit_gap": 1e-3}
 
 
+def digest(arrays: dict) -> str:
+    """sha256 of ``{name: array}``: each array's name, dtype, shape and
+    bytes, in the order of the names."""
+    import hashlib
+
+    import numpy as np
+
+    h = hashlib.sha256()
+    for k in sorted(arrays):
+        a = np.asarray(arrays[k])
+        h.update(f"{k} {a.dtype} {a.shape}".encode())
+        h.update(np.ascontiguousarray(a).tobytes())
+    return h.hexdigest()
+
+
 def make_tree(dest: Path) -> tuple:
     """(tree root, manifest path) of a copy of the benchmark with the
     tiny cell added."""

@@ -147,6 +147,9 @@ def test_the_program_must_run_what_the_configuration_file_states():
         program.check_config(dict(cfg, num_key_value_heads=28), mc)
     with pytest.raises(ValueError, match="norm_eps"):
         program.check_config(dict(cfg, rms_norm_eps=1e-5), mc)
+    looked_for = CHIP / "references" / "no_such_arch.py"
+    with pytest.raises(FileNotFoundError, match=re.escape(str(looked_for))):
+        program.check_config(dict(cfg, architecture="no_such_arch"), mc)
 
 
 def test_weights_are_reproducible_and_orthonormal():
@@ -162,6 +165,18 @@ def test_weights_are_reproducible_and_orthonormal():
     U = a["blocks/mlp/down/U"]
     gram = jnp.einsum("lni,lnj->lij", U, U)
     assert np.allclose(gram, np.eye(U.shape[-1]), atol=1e-5)
+
+
+#: ``tiny.digest`` of the tiny configuration's weights at seed 2**31 + 7,
+#: as the generator made them
+#: when it named the dense decoder's leaves itself
+TINY_WEIGHTS_SHA256 = "973d09fcc2892fe5f9c9b5933598af3a6d0ce3405155c2c3abaffed5424d3613"
+
+
+def test_weights_of_the_tiny_configuration_are_pinned_bit_for_bit():
+    from benchmarks.chip import weights
+
+    assert tiny.digest(weights.generate(tiny.CONFIG, 2**31 + 7)) == TINY_WEIGHTS_SHA256
 
 
 def _run_cli(cwd, *extra):
